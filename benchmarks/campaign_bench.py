@@ -51,11 +51,10 @@ SINGLE_THREAD_ENV = {
 ARCH = "stablelm-1.6b"
 
 
-# NOTE: the jax persistent compilation cache is deliberately NOT used:
-# with jaxlib 0.4.37 on CPU, cache-hitting resumed runs segfault
-# (native heap corruption) after a campaign SIGKILL — found by this
-# bench's chaos leg.  Until the cache is crash-safe, campaign workers
-# pay their own compiles.
+# Campaign workers share jax's persistent compile cache: `python -m
+# repro.launch` points every attempt at one fixed directory
+# (repro/launch/runtime.py), so a retried or resumed attempt reads the
+# compiled step back instead of compiling it again.
 
 
 def build_runs(n: int, steps: int, batch: int, seq: int,

@@ -176,6 +176,8 @@ def _generate_dryrun_artifacts(d: pathlib.Path) -> bool:
     import subprocess
     import sys
     env = dict(os.environ)
+    # the child is a CPU rehearsal: this process may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (str(ROOT / "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
     cmd = [sys.executable, "-m", "repro.launch", "run", "dryrun",

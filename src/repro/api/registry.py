@@ -8,7 +8,8 @@ lazily, so ``import repro.api`` never imports jax.
 A kind may declare process-env prerequisites (``register_runner(...,
 env=...)`` or ``_KIND_ENV`` for the lazy built-ins); ``run()`` applies
 them with ``setdefault`` before the runner module — and therefore jax —
-loads.  That makes the dryrun/perfprobe fake-device trick work whenever
+loads.  That makes the dryrun/perfprobe CPU rehearsal (``JAX_PLATFORMS=
+cpu`` and 512 fake devices) work whenever
 the fake-device kind is the first jax user in the process; if another
 kind already initialized the backend with fewer devices, the mesh layer
 raises an actionable error (jax cannot resize a live backend).
@@ -57,12 +58,15 @@ _LAZY_BUILTINS = {
     "simulate": "repro.api.runners.simulate",
 }
 
-_FAKE_DEVICES = {"XLA_FLAGS": "--xla_force_host_platform_device_count=512"}
+# dryrun/perfprobe are CPU rehearsals: they lower against a 512-device
+# CPU mesh and never claim an accelerator another process may hold
+_CPU_REHEARSAL = {"JAX_PLATFORMS": "cpu",
+                  "XLA_FLAGS": "--xla_force_host_platform_device_count=512"}
 # per-kind process-env prerequisites, applied (setdefault) by run()
 # before the runner module loads
 _KIND_ENV: Dict[str, Dict[str, str]] = {
-    "dryrun": _FAKE_DEVICES,       # lower against the 512-chip CPU mesh
-    "perfprobe": _FAKE_DEVICES,
+    "dryrun": _CPU_REHEARSAL,
+    "perfprobe": _CPU_REHEARSAL,
 }
 
 

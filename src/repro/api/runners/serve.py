@@ -19,6 +19,7 @@ from repro.api.registry import register_runner
 from repro.api.spec import RunSpec
 
 DEFAULTS = {
+    "full": False,          # full-size config instead of reduced
     "requests": 16,
     "slots": 4,
     "cache_len": 128,
@@ -42,7 +43,8 @@ def run_serve(spec: RunSpec) -> RunReport:
     o = spec.merged_overrides(DEFAULTS)
     t0 = time.time()
     result = serve_main(
-        spec.arch, requests=int(o["requests"]), slots=int(o["slots"]),
+        spec.arch, full=bool(o["full"]), requests=int(o["requests"]),
+        slots=int(o["slots"]),
         cache_len=int(o["cache_len"]), max_tokens=int(o["max_tokens"]),
         seed=spec.seed, temperature=float(o["temperature"]),
         top_k=int(o["top_k"]), arrival_rate=float(o["arrival_rate"]),

@@ -115,11 +115,25 @@ def read_manifest(directory: str) -> dict:
     return manifest
 
 
+def _as_declared(arr: np.ndarray, dtype: str) -> np.ndarray:
+    """``np.savez`` stores dtypes numpy does not define (bfloat16 and
+    the other ml_dtypes) as raw ``|V<n>`` bytes; view them back as the
+    dtype the manifest declares.  A view, not a cast: no cast exists from
+    raw bytes, and the bits are the checkpoint's."""
+    want = np.dtype(jax.numpy.dtype(dtype))
+    if arr.dtype.kind == "V" and arr.dtype.itemsize == want.itemsize:
+        return arr.view(want)
+    return arr
+
+
 def load_checkpoint(directory: str, like=None):
     """Returns (tree_or_flat_dict, step).  With ``like`` provided, leaves
     are restored into that pytree structure (shape-checked; dtype-only
     mismatches are cast to the ``like`` leaf's dtype, so e.g. a float32
-    checkpoint restores into a bf16 state and vice versa)."""
+    checkpoint restores into a bf16 state and vice versa).  A leaf of
+    ``like`` that is a ``jax.ShapeDtypeStruct`` restores to a host array,
+    which lets a caller free its device copy before placing the restored
+    one; any other leaf restores to a device array."""
     d = Path(directory)
     manifest = read_manifest(d)
     flat: Dict[str, np.ndarray] = {}
@@ -130,7 +144,8 @@ def load_checkpoint(directory: str, like=None):
         try:
             with np.load(d / fname) as z:
                 for k in keys:
-                    flat[k] = z[k.replace("/", "|")]
+                    flat[k] = _as_declared(z[k.replace("/", "|")],
+                                           manifest["keys"][k]["dtype"])
         except (FileNotFoundError, zipfile.BadZipFile, OSError, EOFError,
                 KeyError, ValueError) as e:
             raise CheckpointError(
@@ -155,7 +170,8 @@ def load_checkpoint(directory: str, like=None):
         want = getattr(leaf, "dtype", None) or np.asarray(leaf).dtype
         if arr.dtype != want:          # dtype-only mismatch: cast, don't crash
             arr = arr.astype(want)
-        new_leaves.append(jax.numpy.asarray(arr, dtype=want))
+        new_leaves.append(arr if isinstance(leaf, jax.ShapeDtypeStruct)
+                          else jax.numpy.asarray(arr, dtype=want))
     tree = jax.tree_util.tree_unflatten(
         jax.tree_util.tree_structure(like), new_leaves)
     return tree, manifest["step"]

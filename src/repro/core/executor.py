@@ -1823,13 +1823,14 @@ class CampaignExecutor:
             # one subprocess per rank, all admitted already (placements);
             # rank 0 hosts the jax.distributed coordinator and its log
             # carries the gang's RunReport
-            from repro.distributed.gang import free_port, rank_argv
+            from repro.distributed.gang import (free_port, rank_argv,
+                                                rank_envs)
             coordinator = f"127.0.0.1:{free_port()}"
             gang_id = f"{job.name}.g{seq}"
             procs: List[Any] = []
             out_p = err_p = None
             out_fh = err_fh = None
-            for r in range(gang):
+            for r, rank_env in enumerate(rank_envs(env, gang)):
                 o_p = self.pvc.path(
                     f"logs/{job.name}.attempt{seq}.rank{r}.out")
                 e_p = self.pvc.path(
@@ -1838,7 +1839,7 @@ class CampaignExecutor:
                 ofh, efh = open(o_p, "wb"), open(e_p, "wb")
                 child = self.spawn(job, seq,
                                    rank_argv(argv, r, coordinator),
-                                   env, ofh, efh)
+                                   rank_env, ofh, efh)
                 procs.append(child)
                 cpid = getattr(child, "pid", None)
                 rank_meta.append({
@@ -1906,7 +1907,7 @@ class CampaignExecutor:
             fields.update(gang=gang, placements=placements,
                           gang_nodes=len(set(placements or [node])))
         if eff is not rec.spec.resources:
-            fields["learned_request"] = {"cpus": eff.cpus,
+            fields["learned_request"] = {"gpus": eff.gpus, "cpus": eff.cpus,
                                          "memory_gb": eff.memory_gb}
         if backfill:
             self._backfills += 1

@@ -43,14 +43,13 @@ def init_distributed(world_size: int = 1, rank: int = 0,
         # not query the backend here (jax.default_backend() would
         # initialize it, which forbids distributed init) — the setting
         # is inert on GPU/TPU.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except AttributeError:       # pragma: no cover - older jaxlib
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        # every parameter is given: no cluster environment is probed
+        # (the TPU probes ask a metadata server, which a host may lack)
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=world_size,
-                                   process_id=int(rank))
+                                   process_id=int(rank),
+                                   cluster_detection_method="deactivate")
         devices = np.array(jax.devices())
     else:
         devices = np.array(jax.devices()[:1])

@@ -10,6 +10,7 @@ process handles) — see ``core/executor.py``.
 """
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import socket
@@ -18,7 +19,10 @@ import sys
 import tempfile
 import time
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Mapping, Optional
+
+# libtpu process bounds for a gang of N one-chip ranks on one 2x2 host
+_PROCESS_BOUNDS = {1: "1,1,1", 2: "2,1,1", 4: "2,2,1"}
 
 
 def free_port(host: str = "127.0.0.1") -> int:
@@ -34,6 +38,40 @@ def rank_argv(base_argv: List[str], rank: int, coordinator: str
     """Append the per-rank distributed flags to a ``run train`` argv."""
     return list(base_argv) + [f"--dist_rank={rank}",
                               f"--coordinator={coordinator}"]
+
+
+def tpu_chips() -> int:
+    """TPU chips this host exposes, from their device nodes (no jax
+    import: the caller spawns the processes that will hold them)."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def rank_envs(env: Mapping[str, str], world: int) -> List[Dict[str, str]]:
+    """One environment per rank of a ``world``-rank gang.  Where the
+    ranks will run on TPU (a host with chips, and ``JAX_PLATFORMS`` not
+    excluding tpu), each rank is bound to its own chip before jax loads,
+    through libtpu's per-process environment: its visible chip, the
+    gang's process bounds and addresses, its port and task id.  Without
+    that every rank would open every chip of the host.  Elsewhere each
+    rank gets ``env`` as it is."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    chips = tpu_chips()
+    if not chips or (platforms and "tpu" not in platforms.split(",")):
+        return [dict(env) for _ in range(world)]
+    if world > chips or world not in _PROCESS_BOUNDS:
+        raise ValueError(f"cannot bind a gang of {world} ranks to one chip "
+                         f"each on a host with {chips} chips")
+    ports = [free_port() for _ in range(world)]
+    # a per-process chip bound smaller than the host lets libtpu load
+    # once per rank; the host-wide load lock stays on
+    gang = {"TPU_PROCESS_BOUNDS": _PROCESS_BOUNDS[world],
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}"
+                                              for p in ports)}
+    return [{**env, **gang, "TPU_VISIBLE_CHIPS": str(r),
+             "TPU_PROCESS_PORT": str(ports[r]), "CLOUD_TPU_TASK_ID": str(r)}
+            for r in range(world)]
 
 
 def _src_path() -> str:
@@ -72,12 +110,12 @@ def run_gang_local(spec, world: int, *,
         prefix=f"gang-{spec.run_name}-"))
     logs.mkdir(parents=True, exist_ok=True)
     procs, outs = [], []
-    for r in range(world):
+    for r, rank_env in enumerate(rank_envs(env, world)):
         out_p = logs / f"rank{r}.out"
         err_p = logs / f"rank{r}.err"
         outs.append(out_p)
         procs.append(subprocess.Popen(
-            rank_argv(base, r, coordinator), env=env,
+            rank_argv(base, r, coordinator), env=rank_env,
             stdout=open(out_p, "wb"), stderr=open(err_p, "wb")))
     rcs: List[Optional[int]] = [None] * world
     try:
@@ -116,11 +154,15 @@ def run_gang_local(spec, world: int, *,
         raise RuntimeError(
             f"gang rank {bad}/{world} exited rc={rcs[bad]} "
             f"(all rcs={rcs}); stderr tail:\n{err_tail}")
-    report = parse_trailing_report(outs[0].read_text(errors="replace"))
-    if report is None or report.get("status") == "failed":
-        raise RuntimeError(f"gang rank 0 produced no usable RunReport "
-                           f"(see {outs[0]})")
-    metrics = dict(report.get("metrics") or {})
+    reports = [parse_trailing_report(o.read_text(errors="replace"))
+               for o in outs]
+    for r, rep in enumerate(reports):
+        if rep is None or rep.get("status") == "failed":
+            raise RuntimeError(f"gang rank {r} produced no usable "
+                               f"RunReport (see {outs[r]})")
+    metrics = dict(reports[0].get("metrics") or {})
     metrics["gang"] = {"world_size": world, "coordinator": coordinator,
-                       "returncodes": rcs, "log_dir": str(logs)}
+                       "returncodes": rcs, "log_dir": str(logs),
+                       "rank_devices": [(rep.get("metrics") or {}).get(
+                           "device") for rep in reports]}
     return metrics

@@ -36,12 +36,13 @@ class DistributedTrainLoop:
         from repro.train import TrainLoop
 
         class _Loop(TrainLoop):
+            def place_state(self, host_state):
+                # the restored host arrays become fully-replicated global
+                # arrays on the mesh
+                return ctx.replicate(host_state)
+
             def resume(self) -> bool:
                 restored = super().resume()
-                if restored:
-                    # restore_latest yields host arrays; lift them back
-                    # to fully-replicated global arrays on the mesh
-                    self.state = ctx.replicate(self.state)
                 ctx.agree(np.asarray(self.start_step, dtype=np.int64),
                           "resumed step")
                 return restored
@@ -90,6 +91,8 @@ def dist_train_main(arch: str, *, world_size: int, dist_rank: int = 0,
     from repro.data.inputs import SeekableSyntheticBatches
     from repro.data.tokens import SeekableTokenBatches
     from repro.distributed.data import ShardedBatches
+    from repro.kernels.common import kernel_paths
+    from repro.launch.runtime import compile_stats, device_report
     from repro.optim import get_optimizer, warmup_cosine
     from repro.sharding import ShardCtx, rules
     from repro.sharding.ctx import use_ctx
@@ -107,8 +110,10 @@ def dist_train_main(arch: str, *, world_size: int, dist_rank: int = 0,
     if backends:
         cfg = dataclasses.replace(cfg, **backends)
     opt = get_optimizer(optimizer or cfg.optimizer)
-    state = init_train_state(jax.random.PRNGKey(seed), cfg, opt)
-    state = ctx.replicate(jax.tree.map(np.asarray, state))
+    # through host memory, so one device copy exists at a time
+    state = jax.tree.map(np.asarray, init_train_state(
+        jax.random.PRNGKey(seed), cfg, opt))
+    state = ctx.replicate(state)
 
     # the existing donated/bf16/Pallas step, bare (jit_compile=False is
     # documented for exactly this: sharded launchers add their own jit)
@@ -146,18 +151,22 @@ def dist_train_main(arch: str, *, world_size: int, dist_rank: int = 0,
             async_saves=bool(checkpoint_async) and ctx.is_coordinator)
     # only the coordinator saves on SIGTERM (it owns checkpoint writes);
     # other ranks die with the signal and the gang requeues as one
-    loop = DistributedTrainLoop.create(
-        step_fn, state, data, ctx=ctx, checkpointer=ckpt,
-        preempt_at_step=preempt_at_step,
-        log_every=log_every if ctx.is_coordinator else 0,
-        sigterm_save=ctx.is_coordinator)
-    if resume:
-        loop.resume()
-    try:
-        run = loop.run(steps)
-    finally:
-        if ckpt is not None:
-            ckpt.wait()
+    with compile_stats() as compiled:
+        # the loop owns the only reference: resume frees it before
+        # restoring
+        loop = DistributedTrainLoop.create(
+            step_fn, state, data, ctx=ctx, checkpointer=ckpt,
+            preempt_at_step=preempt_at_step,
+            log_every=log_every if ctx.is_coordinator else 0,
+            sigterm_save=ctx.is_coordinator)
+        del state
+        if resume:
+            loop.resume()
+        try:
+            run = loop.run(steps)
+        finally:
+            if ckpt is not None:
+                ckpt.wait()
 
     param_bytes = sum(
         int(np.prod(p.shape)) * 4
@@ -165,6 +174,9 @@ def dist_train_main(arch: str, *, world_size: int, dist_rank: int = 0,
     result: Dict[str, Any] = {
         "arch": cfg.name, "params": cfg.param_count(),
         **run,
+        "device": device_report(),
+        "kernels": kernel_paths(cfg, mesh_devices=ctx.devices),
+        "compile": compiled,
         "dist": {
             "world_size": ctx.world_size,
             "rank": ctx.rank,

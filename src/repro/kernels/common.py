@@ -27,3 +27,18 @@ def resolve_backend(backend: str) -> str:
     if backend == "auto":
         return "jnp" if default_interpret() else "pallas"
     return backend
+
+
+def kernel_paths(cfg, mesh_devices: int = 1) -> dict:
+    """The attention and SSD-mixer lowerings a run of ``cfg`` takes, as
+    ``attn_apply``/``ssm_apply`` choose them: the resolved backend on one
+    device, the jnp lowerings under a multi-device mesh (``pallas_call``
+    has no partitioning rule)."""
+    def path(backend):
+        return "jnp" if mesh_devices > 1 else resolve_backend(backend)
+    paths = {}
+    if cfg.has_attention:
+        paths["attention"] = path(cfg.attention_backend)
+    if cfg.ssm is not None:
+        paths["mixer"] = path(cfg.mixer_backend)
+    return paths

@@ -81,15 +81,18 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
     m_ref[...] = m_new
     pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                             (((1,), (0,)), ((), ()))).astype(jnp.float32)
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * alpha + pv
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        # logsumexp residual for the backward pass
-        lse_ref[0] = (m_ref[...] + jnp.log(l))[:, 0]
+        # logsumexp residual for the backward pass, kept as a (bq, 1)
+        # column: the block's last dim then equals the array's, which is
+        # the layout Mosaic accepts for a per-row vector
+        lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention_fwd_kernel(q, k, v, *, causal: bool, window, sq: int,
@@ -97,7 +100,7 @@ def flash_attention_fwd_kernel(q, k, v, *, causal: bool, window, sq: int,
                                interpret: bool | None = None):
     """q: (BH, Sq_pad, hd); k/v: (BKH, Sk_pad, hd).  Sq_pad % block_q == 0,
     Sk_pad % block_k == 0.  BH % BKH == 0 (GQA).  Returns (out, lse) with
-    lse: (BH, Sq_pad) f32.
+    lse: (BH, Sq_pad, 1) f32.
 
     ``interpret=None`` auto-detects: compiled on TPU, interpret elsewhere.
     """
@@ -124,11 +127,11 @@ def flash_attention_fwd_kernel(q, k, v, *, causal: bool, window, sq: int,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, sq_pad, hd), q.dtype),
-            jax.ShapeDtypeStruct((BH, sq_pad), jnp.float32),
+            jax.ShapeDtypeStruct((BH, sq_pad, 1), jnp.float32),
         ],
         scratch_shapes=_scratch(block_q, hd),
         interpret=interpret,
@@ -162,12 +165,12 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     mask = _tile_mask(iq, ik, block_q=block_q, block_k=block_k,
                       causal=causal, window=window, sk=sk, shape=s.shape)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0][:, None])               # (bq, bk)
+    p = jnp.exp(s - lse_ref[0])                        # (bq, bk)
 
     do = do_ref[0].astype(jnp.float32)                 # (bq, hd)
     dp = jax.lax.dot_general(                          # dO V^T: (bq, bk)
         do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta_ref[0][:, None])              # (bq, bk)
+    ds = p * (dp - delta_ref[0])                       # (bq, bk)
     dq_acc[...] += jax.lax.dot_general(                # dS K: (bq, hd)
         ds, k, (((1,), (0,)), ((), ()))) * scale
 
@@ -194,14 +197,14 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     mask = _tile_mask(iq, ik, block_q=block_q, block_k=block_k,
                       causal=causal, window=window, sk=sk, shape=s.shape)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0][:, None])               # (bq, bk)
+    p = jnp.exp(s - lse_ref[0])                        # (bq, bk)
 
     do = do_ref[0].astype(jnp.float32)                 # (bq, hd)
     dv_acc[...] += jax.lax.dot_general(                # P^T dO: (bk, hd)
         p, do, (((0,), (0,)), ((), ())))
     dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
                              (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta_ref[0][:, None])              # (bq, bk)
+    ds = p * (dp - delta_ref[0])                       # (bq, bk)
     dk_acc[...] += jax.lax.dot_general(                # dS^T Q: (bk, hd)
         ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ()))) * scale
 
@@ -215,7 +218,7 @@ def flash_attention_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                                window, sk: int, block_q: int, block_k: int,
                                interpret: bool | None = None):
     """Backward pass.  q/do: (BH, Sq_pad, hd); k/v: (BKH, Sk_pad, hd);
-    lse/delta: (BH, Sq_pad) f32 (delta = rowsum(dO * O)).
+    lse/delta: (BH, Sq_pad, 1) f32 (delta = rowsum(dO * O)).
 
     Returns (dq (BH, Sq_pad, hd), dk, dv (BH, Sk_pad, hd)) — dk/dv at
     *query*-head granularity; the caller reduces over the GQA group.
@@ -242,8 +245,8 @@ def flash_attention_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b // n_rep, j, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b // n_rep, j, 0)),
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, sq_pad, hd), jnp.float32),
@@ -262,8 +265,8 @@ def flash_attention_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b // n_rep, j, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b // n_rep, j, 0)),
             pl.BlockSpec((1, block_q, hd), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0)),

@@ -94,7 +94,8 @@ def _flash_bwd(causal, window, block_q, block_k, interpret, res, g):
     gf = to_padded(g)
     # D = rowsum(dO * O): padded rows have dO = 0, so D = 0 there
     delta = jnp.sum(gf.astype(jnp.float32)
-                    * to_padded(out).astype(jnp.float32), axis=-1)
+                    * to_padded(out).astype(jnp.float32), axis=-1,
+                    keepdims=True)
     dqf, dkf, dvf = flash_attention_bwd_kernel(
         qf, kf, vf, gf, lse, delta, causal=causal, window=window, sk=Sk,
         block_q=bq, block_k=bk, interpret=interpret)

@@ -19,7 +19,7 @@ from repro.kernels.common import default_interpret
 from repro.kernels.ssd_scan.kernel import ssd_scan_kernel
 
 
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, head_block: int = 8,
+def ssd_scan(x, dt, A, B, C, *, chunk: int = 128,
              interpret: bool | None = None, return_state: bool = False):
     """SSD selective scan.  x: (Bs,S,nh,hp); dt: (Bs,S,nh); A: (nh,);
     B/C: (Bs,S,g,N) group-shared.  Returns y: (Bs,S,nh,hp), or
@@ -30,7 +30,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, head_block: int = 8,
     """
     if interpret is None:
         interpret = default_interpret()
-    y, h = _ssd_scan(x, dt, A, B, C, chunk, head_block, interpret)
+    y, h = _ssd_scan(x, dt, A, B, C, chunk, interpret)
     return (y, h) if return_state else y
 
 
@@ -50,35 +50,29 @@ def _pad_chunk(x, dt, B, C, Q, pad):
     return x, dt, B, C
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _ssd_scan_vjp(x, dt, A, B, C, chunk, head_block, interpret):
-    return _ssd_fwd_impl(x, dt, A, B, C, chunk, head_block, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _ssd_scan_vjp(x, dt, A, B, C, chunk, interpret):
+    return _ssd_fwd_impl(x, dt, A, B, C, chunk, interpret)
 
 
-def _ssd_fwd_impl(x, dt, A, B, C, chunk, head_block, interpret):
+def _ssd_fwd_impl(x, dt, A, B, C, chunk, interpret):
     Bs, S, nh, hp = x.shape
-    g = B.shape[2]
-    rep = nh // g
+    g, N = B.shape[2], B.shape[3]
     Q, pad = _chunk_geometry(S, chunk)
     x, dt, B, C = _pad_chunk(x, dt, B, C, Q, pad)
-    Sp = S + pad
-    nc = Sp // Q
+    nc = (S + pad) // Q
 
-    hb = head_block
-    while nh % hb:
-        hb //= 2
-    hb = max(hb, 1)
+    def chunked(a, width):
+        # (Bs, Sp, heads, width) -> head-major (Bs, heads, nc, Q, width)
+        return jnp.moveaxis(a, 2, 1).reshape(Bs, a.shape[2], nc, Q, width)
 
-    Bh = jnp.repeat(B, rep, axis=2)
-    Ch = jnp.repeat(C, rep, axis=2)
-    xq = x.reshape(Bs, nc, Q, nh, hp)
-    dtq = dt.reshape(Bs, nc, Q, nh)
-    Bq = Bh.reshape(Bs, nc, Q, nh, -1)
-    Cq = Ch.reshape(Bs, nc, Q, nh, -1)
-
-    y, h = ssd_scan_kernel(xq, dtq, A, Bq, Cq, chunk=Q, head_block=hb,
-                           interpret=interpret)
-    return y.reshape(Bs, Sp, nh, hp)[:, :S], h
+    dtf = dt.astype(jnp.float32)
+    dtc = chunked(jnp.stack([dtf, dtf * A.astype(jnp.float32)], -1), 2)
+    dtr = jnp.swapaxes(dtc, -1, -2)
+    y, h = ssd_scan_kernel(chunked(x, hp), dtc, dtr, chunked(B, N),
+                           chunked(C, N), interpret=interpret)
+    y = jnp.moveaxis(y.reshape(Bs, nh, nc * Q, hp), 1, 2)
+    return y[:, :S], h
 
 
 def _ssd_jnp_equiv(x, dt, A, B, C, chunk):
@@ -126,12 +120,12 @@ def _ssd_jnp_equiv(x, dt, A, B, C, chunk):
     return y.astype(in_dtype), h_final
 
 
-def _ssd_fwd(x, dt, A, B, C, chunk, head_block, interpret):
-    y, h = _ssd_fwd_impl(x, dt, A, B, C, chunk, head_block, interpret)
+def _ssd_fwd(x, dt, A, B, C, chunk, interpret):
+    y, h = _ssd_fwd_impl(x, dt, A, B, C, chunk, interpret)
     return (y, h), (x, dt, A, B, C)
 
 
-def _ssd_bwd(chunk, head_block, interpret, res, cts):
+def _ssd_bwd(chunk, interpret, res, cts):
     x, dt, A, B, C = res
     _, vjp_fn = jax.vjp(
         lambda x, dt, A, B, C: _ssd_jnp_equiv(x, dt, A, B, C, chunk),
@@ -140,4 +134,4 @@ def _ssd_bwd(chunk, head_block, interpret, res, cts):
 
 
 _ssd_scan_vjp.defvjp(_ssd_fwd, _ssd_bwd)
-_ssd_scan = jax.jit(_ssd_scan_vjp, static_argnums=(5, 6, 7))
+_ssd_scan = jax.jit(_ssd_scan_vjp, static_argnums=(5, 6))

@@ -58,7 +58,9 @@ def _apply_cpu_affinity() -> None:
 
 
 def main(argv=None) -> int:
+    from repro.launch.runtime import use_compile_cache
     _apply_cpu_affinity()
+    use_compile_cache()               # before jax loads; children inherit it
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help", "help"):
         print(f"usage: python -m repro.launch <run|campaign|kinds> ..."

@@ -22,12 +22,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"backend initialized with {n}; on CPU, set XLA_FLAGS="
             f"--xla_force_host_platform_device_count=512 before any jax "
             f"use (a fresh process — the backend cannot be resized)")
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    # older jax (< 0.5): meshes are Auto-typed by default
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():

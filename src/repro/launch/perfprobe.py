@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")   # a CPU rehearsal
 
 # perf-iteration probe: lower+compile one (arch x shape x layout) cell and
 # report MEASURED quantities — trip-count-scaled collective bytes from the

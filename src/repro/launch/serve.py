@@ -29,7 +29,9 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_reduced
+from repro.configs import get_config, get_reduced
+from repro.kernels.common import kernel_paths
+from repro.launch.runtime import compile_stats, device_report
 from repro.models import init_params
 from repro.serve import Request, ServeEngine, ServeScheduler, make_trace
 
@@ -40,24 +42,36 @@ def _timing_metrics(stats_summary: dict) -> dict:
     return {k: stats_summary.get(k) for k in keys}
 
 
-def serve_main(arch: str, *, requests: int = 16, slots: int = 4,
-               cache_len: int = 128, max_tokens: int = 16,
+def serve_main(arch: str, *, full: bool = False, requests: int = 16,
+               slots: int = 4, cache_len: int = 128, max_tokens: int = 16,
                seed: int = 0, temperature: float = 0.0,
                top_k: int = 0, arrival_rate: float = 0.0,
                trace: str = "poisson", slo_deadline_ms: float = 0.0,
                max_kv_blocks: int = 0, kv_block_size: int = 16) -> dict:
-    cfg = get_reduced(arch)
-    params = init_params(jax.random.PRNGKey(seed), cfg)
+    cfg = get_config(arch) if full else get_reduced(arch)
+    with compile_stats() as compiled:
+        params = init_params(jax.random.PRNGKey(seed), cfg)
+        if arrival_rate > 0:
+            result = _serve_continuous(
+                cfg, params, requests=requests, slots=slots,
+                cache_len=cache_len, max_tokens=max_tokens, seed=seed,
+                temperature=temperature, top_k=top_k,
+                arrival_rate=arrival_rate, trace=trace,
+                slo_deadline_ms=slo_deadline_ms,
+                max_kv_blocks=max_kv_blocks, kv_block_size=kv_block_size)
+        else:
+            result = _serve_static(
+                cfg, params, requests=requests, slots=slots,
+                cache_len=cache_len, max_tokens=max_tokens, seed=seed,
+                temperature=temperature, top_k=top_k)
+    # prefill runs attn_apply's path; decode always attends in jnp
+    return {**result, "device": device_report(),
+            "kernels": {"prefill": kernel_paths(cfg), "decode": "jnp"},
+            "compile": compiled}
 
-    if arrival_rate > 0:
-        return _serve_continuous(
-            cfg, params, requests=requests, slots=slots,
-            cache_len=cache_len, max_tokens=max_tokens, seed=seed,
-            temperature=temperature, top_k=top_k,
-            arrival_rate=arrival_rate, trace=trace,
-            slo_deadline_ms=slo_deadline_ms, max_kv_blocks=max_kv_blocks,
-            kv_block_size=kv_block_size)
 
+def _serve_static(cfg, params, *, requests, slots, cache_len, max_tokens,
+                  seed, temperature, top_k) -> dict:
     engine = ServeEngine(cfg, params, slots=slots, cache_len=cache_len,
                          seed=seed)
     rng = np.random.default_rng(seed)
@@ -126,6 +140,8 @@ def main():
     # thin shim over the repro.api registry (RunSpec in, RunReport out)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
+    ap.add_argument("--full", action="store_true",
+                    help="full-size config instead of reduced")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--cache-len", type=int, default=128)
@@ -148,7 +164,7 @@ def main():
 
     from repro.api import RunSpec, run
     report = run(RunSpec(kind="serve", arch=args.arch, overrides={
-        "requests": args.requests, "slots": args.slots,
+        "full": args.full, "requests": args.requests, "slots": args.slots,
         "cache_len": args.cache_len, "max_tokens": args.max_tokens,
         "temperature": args.temperature, "top_k": args.top_k,
         "arrival_rate": args.arrival_rate, "trace": args.trace,

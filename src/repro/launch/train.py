@@ -31,6 +31,8 @@ from repro.configs import get_config, get_reduced
 from repro.core.artifacts import S3Store
 from repro.data.inputs import SeekableSyntheticBatches
 from repro.data.tokens import SeekableTokenBatches
+from repro.kernels.common import kernel_paths
+from repro.launch.runtime import compile_stats, device_report
 from repro.optim import get_optimizer, warmup_cosine
 from repro.train import TrainLoop, init_train_state, make_train_step
 
@@ -63,7 +65,6 @@ def train_main(arch: str, *, reduced: bool = True, steps: int = 100,
     if backends:
         cfg = dataclasses.replace(cfg, **backends)
     opt = get_optimizer(optimizer or cfg.optimizer)
-    state = init_train_state(jax.random.PRNGKey(seed), cfg, opt)
     # jit + donation live in make_train_step: the input TrainState is
     # consumed each step (params/opt_state updated in place)
     step_fn = make_train_step(
@@ -82,19 +83,26 @@ def train_main(arch: str, *, reduced: bool = True, steps: int = 100,
                                  keep_last=max(int(checkpoint_keep), 1),
                                  every_steps=int(checkpoint_every),
                                  async_saves=bool(checkpoint_async))
-    loop = TrainLoop(step_fn, state, data, checkpointer=ckpt,
-                     preempt_at_step=preempt_at_step, log_every=log_every)
-    if resume:
-        loop.resume()
-    try:
-        run = loop.run(steps)
-    finally:
-        if ckpt is not None:
-            ckpt.wait()
+    with compile_stats() as compiled:
+        # the loop holds the only reference to the state: resume frees it
+        # before the restored one lands on the device
+        loop = TrainLoop(step_fn, init_train_state(jax.random.PRNGKey(seed),
+                                                   cfg, opt),
+                         data, checkpointer=ckpt,
+                         preempt_at_step=preempt_at_step, log_every=log_every)
+        if resume:
+            loop.resume()
+        try:
+            run = loop.run(steps)
+        finally:
+            if ckpt is not None:
+                ckpt.wait()
 
     result = {
         "arch": cfg.name, "params": cfg.param_count(),
         **run,
+        "device": device_report(), "kernels": kernel_paths(cfg),
+        "compile": compiled,
     }
     if steps <= 512:
         # oracle tests compare full trajectories (e.g. an elastically

@@ -30,6 +30,8 @@ import signal
 import time
 from typing import Any, Callable, Dict, Optional
 
+import jax
+
 from repro.checkpoint.manager import CheckpointManager
 
 
@@ -90,17 +92,27 @@ class TrainLoop:
         and data cursor.  Returns True when something was restored."""
         if self.checkpointer is None:
             return False
-        restored = self.checkpointer.restore_latest(like=self.state)
+        # restore to host against the state's shapes, and free the device
+        # state before the restored one lands: a full-width TrainState
+        # does not fit on one chip twice
+        restored = self.checkpointer.restore_latest(like=jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.state))
         if restored is None:
             return False
         state, step, extra = restored
-        self.state = state
+        self.state = None
+        self.state = self.place_state(state)
         self.start_step = int(step)
         self.resumed_from_step = int(step)
         cursor = extra.get("data_cursor")
         if cursor is not None and hasattr(self.data, "seek"):
             self.data.seek(cursor)
         return True
+
+    def place_state(self, host_state):
+        """Put a restored host TrainState on the device(s) the step runs
+        on (the default device; the data-parallel loop replicates it)."""
+        return jax.device_put(host_state)
 
     # ---------------------------------------------------------------- run
     def run(self, total_steps: int) -> Dict[str, Any]:
@@ -120,6 +132,7 @@ class TrainLoop:
                 old_term = None
         t0 = time.time()
         step_s = 0.0                    # pure step time, ex-checkpointing
+        first_step_s = None             # the first step, compile included
         # environmental straggler injection (a degraded/oversubscribed
         # node in miniature): stall wall-clock per step without touching
         # any math, so a slowed run stays bitwise-identical.  The
@@ -149,6 +162,8 @@ class TrainLoop:
                 self.state, metrics = self.step_fn(self.state, batch)
                 self.losses.append(float(metrics["loss"]))
                 step_s += time.time() - ts
+                if first_step_s is None:
+                    first_step_s = time.time() - ts
                 if self.log_every and (i % self.log_every == 0
                                        or i == total_steps - 1):
                     print(f"step {i:5d} loss {self.losses[-1]:.4f} "
@@ -178,6 +193,7 @@ class TrainLoop:
             "wall_s": round(wall, 2),
             "steps_per_s": round(steps_run / wall, 3) if wall else 0.0,
             "pure_step_s": round(step_s, 3),
+            "first_step_s": first_step_s,
         }
         if self.losses:
             result.update(first_loss=self.losses[0],

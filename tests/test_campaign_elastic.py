@@ -434,7 +434,8 @@ def test_sigterm_salvage_checkpoint_and_bitwise_resume(tmp_path):
 
 
 @pytest.mark.timeout(900)
-def test_drain_midcampaign_completes_all_jobs_bitwise(tmp_path):
+def test_drain_midcampaign_completes_all_jobs_bitwise(tmp_path,
+                                                     monkeypatch):
     """Acceptance (b): a real campaign loses a node to a nodes.json
     shrink mid-flight; the drained node's resident is gracefully
     evicted and requeued, every job completes, the replayed event log
@@ -467,10 +468,14 @@ def test_drain_midcampaign_completes_all_jobs_bitwise(tmp_path):
 
     th = threading.Thread(target=shrink_when_running, daemon=True)
     th.start()
+    # stall every step (the math is untouched) so the drain lands while
+    # the residents still have steps to run, however fast their steps are
+    monkeypatch.setenv("REPRO_STEP_DELAY_S", "0.5")
     recs = orch.run_cluster(workers=2, retry_backoff_base_s=0.0,
                             telemetry=False, grace_s=60.0,
                             attempt_timeout_s=300)
     th.join(timeout=10)
+    monkeypatch.delenv("REPRO_STEP_DELAY_S")
     assert all(recs[f"el{s}"].state == JobState.SUCCEEDED for s in seeds)
     events = _events(pvc)
     drain = next(e for e in events if e["event"] == "node_draining")
@@ -493,7 +498,7 @@ def test_drain_midcampaign_completes_all_jobs_bitwise(tmp_path):
 
 
 @pytest.mark.timeout(900)
-def test_gang_shrink_world2_to_1_matches_world1_losses(tmp_path):
+def test_gang_shrink_world2_to_1_matches_world1_losses(tmp_path, monkeypatch):
     """Acceptance (c): a 2-rank gang (gang_min=1) loses a node
     mid-campaign, shrinks to world=1, resumes from the shared
     rank-agnostic checkpoint, and its post-shrink losses match the
@@ -533,6 +538,9 @@ def test_gang_shrink_world2_to_1_matches_world1_losses(tmp_path):
 
     th = threading.Thread(target=shrink_on_first_checkpoint, daemon=True)
     th.start()
+    # stall every step (the math is untouched) so the drain lands while
+    # the gang still has steps to run, however fast its steps are
+    monkeypatch.setenv("REPRO_STEP_DELAY_S", "0.5")
     recs = orch.run_cluster(workers=2, retry_backoff_base_s=0.0,
                             telemetry=False, grace_s=60.0)
     th.join(timeout=10)

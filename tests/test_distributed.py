@@ -67,6 +67,36 @@ def test_rank_argv_appends_dist_flags():
     assert base[-1] == "--steps=3"       # input untouched
 
 
+def test_rank_envs_bind_one_chip_per_rank_on_a_tpu_host(monkeypatch):
+    from repro.distributed import gang
+    monkeypatch.setattr(gang, "tpu_chips", lambda: 4)
+    base = {"PATH": "/bin", "JAX_PLATFORMS": "tpu"}
+    envs = gang.rank_envs(base, 4)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    # every rank lists the same addresses; each serves on its own port
+    addrs = envs[0]["TPU_PROCESS_ADDRESSES"].split(",")
+    assert all(e["TPU_PROCESS_ADDRESSES"] == envs[0]["TPU_PROCESS_ADDRESSES"]
+               for e in envs)
+    assert addrs == [f"localhost:{e['TPU_PROCESS_PORT']}" for e in envs]
+    assert len(set(addrs)) == 4
+    assert all(e["PATH"] == "/bin" for e in envs)
+    assert "ALLOW_MULTIPLE_LIBTPU_LOAD" not in envs[0]
+    assert "TPU_VISIBLE_CHIPS" not in base          # input untouched
+    with pytest.raises(ValueError):
+        gang.rank_envs(base, 8)                     # more ranks than chips
+
+
+@pytest.mark.parametrize("chips,platforms", [(0, ""), (4, "cpu")])
+def test_rank_envs_leave_cpu_ranks_unbound(monkeypatch, chips, platforms):
+    from repro.distributed import gang
+    monkeypatch.setattr(gang, "tpu_chips", lambda: chips)
+    base = {"JAX_PLATFORMS": platforms} if platforms else {}
+    assert gang.rank_envs(base, 2) == [base, base]
+
+
 def test_gang_manifest_renders_indexed_job():
     job = JobSpec(name="ddp", gang=4)
     spec = job.manifest()["spec"]

@@ -85,18 +85,18 @@ def test_flash_attention_grads_match_ref(case, dtype):
 
 # ------------------------------------------------------------- ssd scan
 SSD_CASES = [
-    # Bs, S, nh, hp, g, N, chunk, head_block
-    (2, 64, 4, 16, 1, 16, 16, 4),
-    (1, 96, 8, 32, 2, 32, 32, 4),
-    (2, 130, 4, 16, 4, 8, 32, 2),    # padding path
-    (1, 128, 2, 64, 1, 64, 64, 2),
+    # Bs, S, nh, hp, g, N, chunk
+    (2, 64, 4, 16, 1, 16, 16),
+    (1, 96, 8, 32, 2, 32, 32),
+    (2, 130, 4, 16, 4, 8, 32),    # padding path
+    (1, 128, 2, 64, 1, 64, 64),
 ]
 
 
 @pytest.mark.parametrize("case", SSD_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ssd_scan_matches_ref(case, dtype):
-    Bs, S, nh, hp, g, N, chunk, hb = case
+    Bs, S, nh, hp, g, N, chunk = case
     ks = jax.random.split(KEY, 5)
     x = jax.random.normal(ks[0], (Bs, S, nh, hp), dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (Bs, S, nh))).astype(
@@ -104,7 +104,7 @@ def test_ssd_scan_matches_ref(case, dtype):
     A = -jnp.exp(jax.random.normal(ks[2], (nh,)) * 0.3)
     B = jax.random.normal(ks[3], (Bs, S, g, N), dtype)
     C = jax.random.normal(ks[4], (Bs, S, g, N), dtype)
-    y = ssd_scan(x, dt, A, B, C, chunk=chunk, head_block=hb)
+    y = ssd_scan(x, dt, A, B, C, chunk=chunk)
     yr, _ = ssd_ref(x, dt, A, B, C)
     tol = 5e-4 if dtype == jnp.float32 else 1e-1
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -113,10 +113,10 @@ def test_ssd_scan_matches_ref(case, dtype):
 
 
 SSD_GRAD_CASES = [
-    # Bs, S, nh, hp, g, N, chunk, head_block
-    (2, 64, 4, 16, 1, 16, 16, 4),
-    (2, 130, 4, 16, 4, 8, 32, 2),    # padding path
-    (1, 96, 8, 32, 2, 32, 32, 4),
+    # Bs, S, nh, hp, g, N, chunk
+    (2, 64, 4, 16, 1, 16, 16),
+    (2, 130, 4, 16, 4, 8, 32),    # padding path
+    (1, 96, 8, 32, 2, 32, 32),
 ]
 
 
@@ -125,7 +125,7 @@ SSD_GRAD_CASES = [
 def test_ssd_scan_grads_match_ref(case, dtype):
     """jax.grad through the Pallas SSD op (custom VJP) agrees with
     autodiff through the sequential-recurrence oracle."""
-    Bs, S, nh, hp, g, N, chunk, hb = case
+    Bs, S, nh, hp, g, N, chunk = case
     ks = jax.random.split(KEY, 6)
     x = jax.random.normal(ks[0], (Bs, S, nh, hp), dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (Bs, S, nh))).astype(
@@ -136,7 +136,7 @@ def test_ssd_scan_grads_match_ref(case, dtype):
     co = jax.random.normal(ks[5], (Bs, S, nh, hp), jnp.float32)
 
     def f(x, dt, A, B, C):
-        y = ssd_scan(x, dt, A, B, C, chunk=chunk, head_block=hb)
+        y = ssd_scan(x, dt, A, B, C, chunk=chunk)
         return jnp.sum(y.astype(jnp.float32) * co)
 
     def f_ref(x, dt, A, B, C):

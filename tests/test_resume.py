@@ -315,6 +315,73 @@ def test_bf16_checkpoint_restores_into_f32_run(tmp_path):
     assert np.isfinite(res["final_loss"])
 
 
+def _bf16_state(arch="stablelm-1.6b"):
+    """A TrainState in the full config's precision (params and AdamW
+    moments bf16) at reduced widths: the full widths do not fit a CPU
+    test, and the dtypes are what the checkpoint path has to carry."""
+    import dataclasses
+    from repro.configs import get_config, get_reduced
+    from repro.train import init_train_state
+    cfg = dataclasses.replace(get_reduced(arch),
+                              param_dtype=get_config(arch).param_dtype)
+    return cfg, init_train_state(jax.random.PRNGKey(0), cfg)
+
+
+def test_bf16_train_state_roundtrips_bitwise(tmp_path):
+    """np.savez stores bf16 leaves as raw bytes; load views them back
+    through the manifest dtype, so every leaf returns bit for bit, with
+    or without a ``like`` tree."""
+    _, state = _bf16_state()
+    assert jax.tree.leaves(state.params)[0].dtype == jnp.bfloat16
+    d = save_checkpoint(tmp_path / "ck", state, step=3)
+    tree, step = load_checkpoint(d, like=state)
+    flat, _ = load_checkpoint(d)
+    assert step == 3
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(tree)):
+        assert a.dtype == b.dtype
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert {str(v.dtype) for v in flat.values()} >= {"bfloat16"}
+
+
+def test_full_config_bf16_checkpoint_restores_through_manager(tmp_path):
+    """A bf16 run killed after a cadence checkpoint restores through
+    ``CheckpointManager.restore_latest`` and ends bitwise identical to
+    the uninterrupted run."""
+    from repro.data.tokens import SeekableTokenBatches
+    from repro.train import make_train_step
+
+    cfg, _ = _bf16_state()
+
+    class Batches(SeekableTokenBatches):
+        def next_batch(self):
+            toks, labels = super().next_batch()
+            return {"tokens": jnp.asarray(toks),
+                    "labels": jnp.asarray(labels)}
+
+    step_fn = make_train_step(cfg)
+
+    def loop(ckpt_dir=None, **kw):
+        mgr = (CheckpointManager(ckpt_dir, every_steps=2, async_saves=False)
+               if ckpt_dir else None)
+        return TrainLoop(step_fn, _bf16_state()[1],
+                         Batches(cfg.vocab, 2, 16, 0), checkpointer=mgr,
+                         log_every=0, **kw)
+
+    base = loop()
+    base.run(6)
+    ck = tmp_path / "ck"
+    with pytest.raises(Preemption):
+        loop(ck, preempt_at_step=5).run(6)
+    resumed = loop(ck)
+    assert resumed.resume() and resumed.start_step == 4
+    assert jax.tree.leaves(resumed.state.params)[0].dtype == jnp.bfloat16
+    resumed.run(6)
+    assert resumed.losses == base.losses[4:]
+    for a, b in zip(jax.tree.leaves(base.state),
+                    jax.tree.leaves(resumed.state)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
 # ------------------------------------------- orchestrator resume semantics
 def test_orchestrator_retry_resumes_from_checkpoint(tmp_path):
     """A payload that raises at step k then succeeds on retry must end at

@@ -206,11 +206,30 @@ def test_pallas_backend_trains_equivalently(arch):
                                                  abs=1e-5)
     assert float(m_jnp["grad_norm"]) == pytest.approx(
         float(m_pl["grad_norm"]), rel=1e-4)
-    for a, b in zip(jax.tree.leaves(s_jnp.params),
-                    jax.tree.leaves(s_pl.params)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   atol=1e-5, rtol=1e-4)
+    # AdamW's first step moves each weight by lr * g / (|g| + eps): the
+    # update is normalized per element.  The kernels sum their products
+    # in another f32 order than the jnp lowerings, so the gradients of a
+    # leaf differ by |dg| <= delta = 16 * eps_f32 * max|g| (the measured
+    # spread stays under 8 * eps_f32 * max|g|).  Where |g| >= 10 * delta
+    # the updates differ by at most lr * delta / |g| <= 1e-5 = atol.  An
+    # element whose gradient lies under 10 * delta, at the leaf's noise
+    # floor, may take any update in [-lr, lr] on either backend: it is
+    # held to 2 * lr.
+    lr = 1e-4                                   # make_train_step default
+    c_jnp = dataclasses.replace(cfg, attention_backend="jnp",
+                                mixer_backend="jnp")
+    grads = jax.grad(lambda p: train_loss(p, c_jnp, batch))(
+        init_train_state(jax.random.PRNGKey(0), c_jnp).params)
+    eps32 = float(np.finfo(np.float32).eps)
+    for a, b, g in zip(jax.tree.leaves(s_jnp.params),
+                       jax.tree.leaves(s_pl.params), jax.tree.leaves(grads)):
+        g = np.abs(np.asarray(g, np.float32))
+        delta = 16 * eps32 * g.max()
+        atol = np.where(g >= 10 * delta, 1e-5, 2 * lr)
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        bad = np.abs(a - b) > atol + 1e-4 * np.abs(b)
+        assert not bad.any(), (f"{bad.sum()} of {bad.size} elements "
+                               f"differ by {np.abs(a - b)[bad].max()}")
 
 
 def test_checkpoint_roundtrip(tmp_path):
