@@ -1,0 +1,9 @@
+"""Puts the benchmark's modules and the program on the import path of the
+benchmark's own tests."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
