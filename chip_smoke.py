@@ -186,7 +186,6 @@ def phase_train(r: Runner) -> dict:
          resumed_from_step=c["resumed_from_step"],
          resumed_losses=c["losses"], resume_match=resume,
          compile_cold=a["compile"], compile_warm=warm,
-         first_step_s={"cold": a["first_step_s"], "warm": c["first_step_s"]},
          peak_bytes_in_use=device.get("peak_bytes_in_use"),
          checkpoint=c.get("checkpoint"), device=device)
     if not all(map(_finite, losses)):

@@ -42,6 +42,21 @@ def _timing_metrics(stats_summary: dict) -> dict:
     return {k: stats_summary.get(k) for k in keys}
 
 
+def _batch_use(engine) -> dict:
+    """The share of prefilled positions that were padding (each prefill
+    runs the full slots x bucket batch) and the share of decode slots
+    that held a live request, from the engine's counters."""
+    st = engine.stats
+    padded, steps = st["prefill_padded_tokens"], st["decode_steps"]
+    return {
+        "prefill_pad_waste": (round(1 - st["prefill_tokens"] / padded, 4)
+                              if padded else None),
+        "decode_occupancy": (round(st["decode_active_slots"]
+                                   / (steps * engine.slots), 4)
+                             if steps else None),
+    }
+
+
 def serve_main(arch: str, *, full: bool = False, requests: int = 16,
                slots: int = 4, cache_len: int = 128, max_tokens: int = 16,
                seed: int = 0, temperature: float = 0.0,
@@ -94,6 +109,7 @@ def _serve_static(cfg, params, *, requests, slots, cache_len, max_tokens,
         "prefill_compiles": engine.prefill_compiles,
         "decode_compiles": engine.decode_compiles,
         "host_transfer_bytes": engine.stats["host_transfer_bytes"],
+        **_batch_use(engine),
         **_timing_metrics(engine.stats()),
     }
 
@@ -132,6 +148,7 @@ def _serve_continuous(cfg, params, *, requests, slots, cache_len,
         "prefill_compiles": s["prefill_compiles"],
         "decode_compiles": s["decode_compiles"],
         "kv": s["kv"],
+        **_batch_use(sched),
         **_timing_metrics(s),
     }
 

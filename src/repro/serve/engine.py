@@ -20,15 +20,23 @@ Device-resident decode loop:
     prompt length.
 
 The only per-token host work is bookkeeping of finished requests.
+Each step's phases (admission, prefill per bucket, decode dispatch, the
+token readback and the per-slot bookkeeping) are host spans
+(:mod:`repro.obs`) in a profiler's trace.
 ``submit`` validates prompts: empty prompts and prompts that cannot fit
 the cache (``len(prompt) >= cache_len``) raise ``ValueError`` instead of
 silently truncating.
 
 Per-request service timing (submit/admit/first-token/done timestamps,
 derived TTFT / TPOT / queue-wait) is recorded against the engine's
-clock; ``engine.stats`` doubles as the raw counter dict (mapping access)
-and, when *called*, returns a summary with latency percentiles — the
-shape campaign ``RunReport`` aggregation expects.
+clock; a request is admitted when it is given a slot, before its
+prefill.  ``engine.stats`` doubles as the raw counter dict (mapping
+access) and, when *called*, returns a summary with latency percentiles —
+the shape campaign ``RunReport`` aggregation expects.  Its counters
+include the real and the padded prompt tokens prefilled
+(``prefill_tokens``, ``prefill_padded_tokens``: slots x bucket per
+call) and the live slots summed over decode steps
+(``decode_active_slots``).
 
 :class:`repro.serve.scheduler.ServeScheduler` builds continuous-batching
 admission (arrival process, SLO shedding, paged-KV eviction, streaming)
@@ -45,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.models import (decode_and_sample, init_decode_state,
                           prefill_and_sample)
@@ -223,7 +232,8 @@ class ServeEngine:
         self._decode_traces = 0
         self.stats = EngineStats(
             self, decode_steps=0, host_transfer_bytes=0, prefill_calls=0,
-            admitted=0)
+            admitted=0, prefill_tokens=0, prefill_padded_tokens=0,
+            decode_active_slots=0)
 
         def fused_decode(p, state, last_tok, pos, base_key, tick,
                          temps, topks, eos, sampling):
@@ -328,9 +338,15 @@ class ServeEngine:
         return pairs
 
     def _admit(self):
-        admitted = self._select_admissions()
+        with obs.span(obs.SERVE_ADMIT) as sp:
+            admitted = self._select_admissions()
+            sp.set_metadata(admitted=len(admitted), queued=len(self.queue))
         if not admitted:
             return
+        now = self.clock.now()
+        for _, req in admitted:
+            if req.t_admit is None:
+                req.t_admit = now
         self._fill_slots(admitted)
         self._sync_slot_meta()
 
@@ -345,45 +361,54 @@ class ServeEngine:
                 (slot, req, toks_np, plen))
 
         for bucket, grp in sorted(groups.items()):
-            # fixed (slots, bucket) prefill batch — rows beyond the group
-            # are dummies (length 0, state discarded by the insert mask)
-            toks = np.zeros((self.slots, bucket), np.int32)
-            lens = np.zeros(self.slots, np.int32)
-            temps = np.zeros(self.slots, np.float32)
-            topks = np.zeros(self.slots, np.int32)
-            src_row = np.full(self.slots, -1, np.int32)
-            for r, (slot, req, toks_np, plen) in enumerate(grp):
-                toks[r, :plen] = toks_np[-plen:]
-                lens[r] = plen
-                temps[r], topks[r] = self._effective_sampling(req)
-                src_row[slot] = r
-            self._tick += 1
-            ptoks, pstate = self._prefill_fn(bucket)(
-                self.params, jnp.asarray(toks), jnp.asarray(lens),
-                self._base_key, np.int32(self._tick), jnp.asarray(temps),
-                jnp.asarray(topks))
-            self.state, self.last_token, self.positions = self._insert(
-                self.state, pstate, self.last_token, self.positions,
-                jnp.asarray(src_row), ptoks, jnp.asarray(lens))
-            first = np.asarray(ptoks)          # (slots,) — admit-time only
-            self.stats["prefill_calls"] += 1
-            now = self.clock.now()
-            for r, (slot, req, toks_np, plen) in enumerate(grp):
-                self.active[slot] = req
-                req.status = RUNNING
-                if req.t_admit is None:
-                    req.t_admit = now
-                tok = int(first[r])
-                req.generated.append(tok)
-                if req.t_first is None:
-                    req.t_first = now
-                self._host_pos[slot] = plen
-                self.stats["admitted"] += 1
-                finished = len(req.generated) >= req.max_tokens
-                if finished:
-                    self._retire(slot, req)
-                if req.on_token:
-                    req.on_token(req, tok, finished)
+            rows = len(grp)
+            tokens = sum(plen for _, _, _, plen in grp)
+            with obs.span(obs.SERVE_PREFILL, bucket=bucket, slots=self.slots,
+                          rows=rows, tokens=tokens,
+                          rids=lambda: " ".join(str(req.rid)
+                                                for _, req, _, _ in grp)):
+                self._prefill_group(bucket, grp)
+            self.stats["prefill_tokens"] += tokens
+            self.stats["prefill_padded_tokens"] += self.slots * bucket
+
+    def _prefill_group(self, bucket: int, grp: list):
+        """One fixed (slots, bucket) prefill batch — rows beyond the group
+        are dummies (length 0, state discarded by the insert mask)."""
+        toks = np.zeros((self.slots, bucket), np.int32)
+        lens = np.zeros(self.slots, np.int32)
+        temps = np.zeros(self.slots, np.float32)
+        topks = np.zeros(self.slots, np.int32)
+        src_row = np.full(self.slots, -1, np.int32)
+        for r, (slot, req, toks_np, plen) in enumerate(grp):
+            toks[r, :plen] = toks_np[-plen:]
+            lens[r] = plen
+            temps[r], topks[r] = self._effective_sampling(req)
+            src_row[slot] = r
+        self._tick += 1
+        ptoks, pstate = self._prefill_fn(bucket)(
+            self.params, jnp.asarray(toks), jnp.asarray(lens),
+            self._base_key, np.int32(self._tick), jnp.asarray(temps),
+            jnp.asarray(topks))
+        self.state, self.last_token, self.positions = self._insert(
+            self.state, pstate, self.last_token, self.positions,
+            jnp.asarray(src_row), ptoks, jnp.asarray(lens))
+        first = np.asarray(ptoks)          # (slots,) — admit-time only
+        self.stats["prefill_calls"] += 1
+        now = self.clock.now()
+        for r, (slot, req, toks_np, plen) in enumerate(grp):
+            self.active[slot] = req
+            req.status = RUNNING
+            tok = int(first[r])
+            req.generated.append(tok)
+            if req.t_first is None:
+                req.t_first = now
+            self._host_pos[slot] = plen
+            self.stats["admitted"] += 1
+            finished = len(req.generated) >= req.max_tokens
+            if finished:
+                self._retire(slot, req)
+            if req.on_token:
+                req.on_token(req, tok, finished)
 
     def _sync_slot_meta(self):
         """Refresh the per-slot sampling/EOS device arrays (admit-time
@@ -415,44 +440,54 @@ class ServeEngine:
     def step(self) -> bool:
         """One decode step across all active slots.  Returns whether a
         decode actually ran (False: nothing active after admission)."""
-        self._admit()
-        return self._decode_tick()
+        with obs.span(obs.SERVE_STEP, tick=self._tick):
+            self._admit()
+            return self._decode_tick()
 
     def _decode_tick(self) -> bool:
         """Decode one token for every active slot (no admission)."""
-        if not any(r is not None for r in self.active):
+        active = self.slots - self.active.count(None)
+        if not active:
             return False
         self._tick += 1
-        self.state, tok, self.positions, eos_hit = \
-            self._decode(self.params, self.state, self.last_token,
-                         self.positions, self._base_key,
-                         np.int32(self._tick), self._temps, self._topks,
-                         self._eos, self._needs_sampling)
+        tick = self._tick
+        with obs.span(obs.SERVE_DECODE, tick=tick, active=active,
+                      slots=self.slots):
+            self.state, tok, self.positions, eos_hit = \
+                self._decode(self.params, self.state, self.last_token,
+                             self.positions, self._base_key,
+                             np.int32(tick), self._temps, self._topks,
+                             self._eos, self._needs_sampling)
         self.last_token = tok
-        # the ONLY per-token device→host transfer: token ids + done flags
-        tok_h = np.asarray(tok)
-        eos_h = np.asarray(eos_hit)
+        # the ONLY per-token device→host transfer: token ids + done flags;
+        # the host waits here for the decode step to end on the device
+        with obs.span(obs.SERVE_READBACK, tick=tick):
+            tok_h = np.asarray(tok)
+            eos_h = np.asarray(eos_hit)
         self.stats["decode_steps"] += 1
+        self.stats["decode_active_slots"] += active
         self.stats["host_transfer_bytes"] += tok_h.nbytes + eos_h.nbytes
         self._host_pos += 1
         self.clock.on_step()
 
-        retired = False
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            tok_i = int(tok_h[slot])
-            req.generated.append(tok_i)
-            finished = (bool(eos_h[slot])
-                        or len(req.generated) >= req.max_tokens
-                        or self._host_pos[slot] >= self.cache_len - 1)
-            if finished:
-                self._retire(slot, req)
-                retired = True
-            if req.on_token:
-                req.on_token(req, tok_i, finished)
-        if retired:
-            self._sync_slot_meta()
+        with obs.span(obs.SERVE_EMIT) as sp:
+            retired = 0
+            for slot, req in enumerate(self.active):
+                if req is None:
+                    continue
+                tok_i = int(tok_h[slot])
+                req.generated.append(tok_i)
+                finished = (bool(eos_h[slot])
+                            or len(req.generated) >= req.max_tokens
+                            or self._host_pos[slot] >= self.cache_len - 1)
+                if finished:
+                    self._retire(slot, req)
+                    retired += 1
+                if req.on_token:
+                    req.on_token(req, tok_i, finished)
+            if retired:
+                self._sync_slot_meta()
+            sp.set_metadata(retired=retired)
         return True
 
     def run(self, max_steps: int = 1000) -> List[Request]:

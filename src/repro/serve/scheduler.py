@@ -43,6 +43,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.serve.engine import (DONE, QUEUED, SHED, Clock, Request,
                                 ServeEngine, validate_request)
@@ -217,9 +218,12 @@ class ServeScheduler(ServeEngine):
 
     # ------------------------------------------------------------ drive
     def step(self) -> bool:
-        self._admit()
-        self._ensure_decode_capacity()
-        return self._decode_tick()
+        with obs.span(obs.SERVE_STEP, tick=self._tick):
+            self._admit()
+            with obs.span(obs.SERVE_CAPACITY) as sp:
+                self._ensure_decode_capacity()
+                sp.set_metadata(kv_blocks_used=self.kv.used_blocks)
+            return self._decode_tick()
 
     def idle(self) -> bool:
         return (not self._pending and not self.queue

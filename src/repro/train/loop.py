@@ -10,7 +10,9 @@ owns
   ``next_batch()/cursor()/seek(cursor)`` — see
   :class:`repro.data.tokens.SeekableTokenBatches`),
 * metrics and throughput accounting (pure step rate vs. checkpoint
-  overhead, reported separately),
+  overhead, reported separately), and host spans of each step's batch,
+  dispatch, loss readback and checkpoint save in a profiler's trace
+  (:mod:`repro.obs`),
 * a :class:`repro.checkpoint.CheckpointManager` for atomic cadence
   checkpoints of the **full** :class:`TrainState` plus the data cursor,
 * resume (``resume()`` restores state + step + data position from the
@@ -32,6 +34,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 
+from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 
 
@@ -132,7 +135,6 @@ class TrainLoop:
                 old_term = None
         t0 = time.time()
         step_s = 0.0                    # pure step time, ex-checkpointing
-        first_step_s = None             # the first step, compile included
         # environmental straggler injection (a degraded/oversubscribed
         # node in miniature): stall wall-clock per step without touching
         # any math, so a slowed run stays bitwise-identical.  The
@@ -158,12 +160,14 @@ class TrainLoop:
                         f"injected preemption before step {i} "
                         f"(completed {i} of {total_steps})")
                 ts = time.time()
-                batch = self.data.next_batch()
-                self.state, metrics = self.step_fn(self.state, batch)
-                self.losses.append(float(metrics["loss"]))
+                with obs.span(obs.TRAIN_NEXT_BATCH, step=i):
+                    batch = self.data.next_batch()
+                with obs.span(obs.TRAIN_STEP, step=i):
+                    self.state, metrics = self.step_fn(self.state, batch)
+                # the host waits here for the step to end on the device
+                with obs.span(obs.TRAIN_LOSS_READBACK, step=i):
+                    self.losses.append(float(metrics["loss"]))
                 step_s += time.time() - ts
-                if first_step_s is None:
-                    first_step_s = time.time() - ts
                 if self.log_every and (i % self.log_every == 0
                                        or i == total_steps - 1):
                     print(f"step {i:5d} loss {self.losses[-1]:.4f} "
@@ -174,7 +178,8 @@ class TrainLoop:
                     extra = {}          # cursor captured only when saving
                     if hasattr(self.data, "cursor"):
                         extra["data_cursor"] = self.data.cursor()
-                    ck.save(self.state, i + 1, extra=extra)
+                    with obs.span(obs.TRAIN_CHECKPOINT, step=i + 1):
+                        ck.save(self.state, i + 1, extra=extra)
             # a SIGTERM that lands during the final step (or after the
             # loop) still checkpoints before the process dies
             if self._sigterm_flag:
@@ -193,7 +198,6 @@ class TrainLoop:
             "wall_s": round(wall, 2),
             "steps_per_s": round(steps_run / wall, 3) if wall else 0.0,
             "pure_step_s": round(step_s, 3),
-            "first_step_s": first_step_s,
         }
         if self.losses:
             result.update(first_loss=self.losses[0],

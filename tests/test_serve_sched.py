@@ -150,6 +150,36 @@ def test_open_loop_arrivals_release_by_clock(params):
     assert a.t_done < b.t_admit             # b really arrived later
 
 
+def test_admit_is_stamped_before_the_prefill(params):
+    """A request is admitted when it is given a slot: its queue wait is
+    the wait behind the full slot, and its own prefill (0.5 s here)
+    falls between admission and its first token."""
+    clock = VirtualClock(dt_per_step=0.01)
+    sched = ServeScheduler(CFG, params, slots=1, cache_len=64, clock=clock)
+    prefill_fn = sched._prefill_fn
+
+    def slow_prefill(bucket):
+        fn = prefill_fn(bucket)
+
+        def run(*args):
+            clock.advance(0.5)
+            return fn(*args)
+        return run
+
+    sched._prefill_fn = slow_prefill
+    hog, waiter = _requests(2, seed=7, max_tokens=4)
+    sched.submit(hog)
+    sched.submit(waiter)
+    sched.run()
+    for r in (hog, waiter):
+        assert r.t_submit <= r.t_admit <= r.t_first
+        assert r.t_first - r.t_admit == pytest.approx(0.5)
+    assert hog.queue_wait_s == 0.0
+    # the hog's prefill and its three decode steps, then the slot frees
+    assert waiter.t_admit == pytest.approx(hog.t_done)
+    assert waiter.queue_wait_s == pytest.approx(0.5 + 3 * 0.01)
+
+
 # -------------------------------------------------------------- streaming
 def test_stream_yields_tokens_and_ttft(params):
     sched = ServeScheduler(CFG, params, slots=2, cache_len=64)
